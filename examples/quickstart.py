@@ -15,17 +15,18 @@ if "--devices" in sys.argv:
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
 import numpy as np
 
 from repro.core import DDF, DDFContext
 from repro.data.synthetic import uniform_table
 from repro.expr import col
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 
 
 def main():
     # env = execution environment (paper's `env=env`)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_host_mesh()
     ctx = DDFContext(mesh=mesh, axes=("data",))
     print(f"workers: {ctx.nworkers}")
 
@@ -61,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.serve import ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

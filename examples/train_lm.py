@@ -29,6 +29,8 @@ from repro.train.checkpoint import save
 from repro.train.elastic import StepGuard
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import TrainHParams, init_train_state, make_train_step
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 
 PRESETS = {
     # ~1M params: fast on this CPU container
@@ -57,7 +59,7 @@ def main():
     model = build_model(cfg)
 
     # ---- DDF data pipeline (the paper's technique as the data path) -------
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_host_mesh()
     ctx = DDFContext(mesh=mesh, axes=("data",))
     pipe = TokenPipeline(ctx, n_docs=4000, vocab=cfg.vocab_size,
                          seq_len=args.seq, batch=args.batch)
@@ -90,4 +92,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,12 +13,14 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import DDF, DDFContext
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 
 
 def main():
     devs = jax.devices()
     print(f"devices: {len(devs)}")
-    mesh = jax.make_mesh((len(devs),), ("data",))
+    mesh = make_host_mesh()
     ctx = DDFContext(mesh=mesh, axes=("data",))
 
     rng = np.random.default_rng(0)
@@ -243,4 +245,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -1,12 +1,7 @@
-"""JAX version compatibility shims.
+"""The JAX surface the codebase enters ``shard_map`` regions through.
 
-The codebase targets the current ``jax.shard_map`` / ``jax.lax.axis_size``
-surface; older jax releases (e.g. 0.4.x) expose the same functionality under
-``jax.experimental.shard_map.shard_map`` (with ``check_rep`` instead of
-``check_vma``) and have no ``axis_size`` (but ``jax.lax.psum(1, axis)``
-constant-folds to a static int inside ``shard_map``). Everything that enters a
-``shard_map`` region goes through these two wrappers so the rest of the code
-is version-agnostic.
+Everything that enters a ``shard_map`` region goes through these wrappers,
+so a change of JAX's API lands in one module.
 """
 
 from __future__ import annotations
@@ -16,69 +11,18 @@ import jax
 __all__ = ["shard_map", "axis_size", "optimization_barrier"]
 
 
-def _native_shard_map():
-    try:
-        return jax.shard_map  # jax >= 0.6 (also jax.experimental alias gone)
-    except AttributeError:
-        return None
-
-
-_NATIVE = _native_shard_map()
-if _NATIVE is None:
-    from jax.experimental.shard_map import shard_map as _EXPERIMENTAL
-else:
-    _EXPERIMENTAL = None
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag: both toggle the
-    per-device replication/varying-axis check.
-    """
-    if _NATIVE is not None:
-        return _NATIVE(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_vma=check_vma)
-    return _EXPERIMENTAL(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=check_vma)
+    """``jax.shard_map`` (``check_vma`` toggles the per-device
+    varying-axis check)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 def axis_size(axis) -> int:
     """Static size of a mapped mesh axis (or tuple of axes) from inside
     ``shard_map``. Returns a Python int usable as a loop bound / shape."""
-    try:
-        return jax.lax.axis_size(axis)
-    except AttributeError:
-        return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)
 
 
-def _native_barrier_differentiates() -> bool:
-    try:
-        jax.jvp(jax.lax.optimization_barrier, (1.0,), (1.0,))
-        return True
-    except Exception:
-        return False
-
-
-if _native_barrier_differentiates():
-    # Newer jax: the primitive has its own differentiation rule (including
-    # forward mode) — use it untouched.
-    optimization_barrier = jax.lax.optimization_barrier
-else:
-    @jax.custom_vjp
-    def optimization_barrier(x):
-        """``jax.lax.optimization_barrier`` with an explicit identity gradient.
-
-        Old jax releases ship the primitive without a differentiation rule;
-        the barrier is semantically the identity, so its VJP passes cotangents
-        through unchanged while keeping the scheduling barrier in the forward.
-        """
-        return jax.lax.optimization_barrier(x)
-
-    def _ob_fwd(x):
-        return optimization_barrier(x), None
-
-    def _ob_bwd(_, g):
-        return (g,)
-
-    optimization_barrier.defvjp(_ob_fwd, _ob_bwd)
+#: ``jax.lax.optimization_barrier``, which differentiates in both modes
+optimization_barrier = jax.lax.optimization_barrier

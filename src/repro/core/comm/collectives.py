@@ -112,14 +112,19 @@ def shuffle_table(table: Table, dest: jax.Array, axis, quota: int,
     else:
         recv_cols = {k: _all_to_all(v, axis) for k, v in bufs.columns.items()}
         recv_counts = _all_to_all(bufs.counts.reshape(P, 1), axis).reshape(P)
-    # validity of the (P, quota) grid
-    keep = jnp.arange(quota, dtype=jnp.int32)[None, :] < recv_counts[:, None]
-    flat_keep = keep.reshape(P * quota)
-    out = Table({k: v.reshape((P * quota,) + v.shape[2:]) for k, v in recv_cols.items()},
-                jnp.asarray(P * quota, jnp.int32))
-    from ..dataframe import compact  # local import to avoid cycle at module load
-    out = compact(out, flat_keep, capacity=capacity)
-    return out, bufs.overflow
+    # slot q of source s is live iff q < recv_counts[s]; it lands at
+    # src_offset[s] + q (source-major, within-source stable), everything
+    # else stays zero — placed by one scatter, not a compaction sort
+    cap_out = P * quota if capacity is None else capacity
+    src_offset = jnp.cumsum(recv_counts) - recv_counts
+    q = jnp.arange(quota, dtype=jnp.int32)
+    live = q[None, :] < recv_counts[:, None]
+    pos = jnp.where(live, src_offset[:, None] + q[None, :], cap_out).reshape(P * quota)
+    cols = {k: jnp.zeros((cap_out,) + v.shape[2:], v.dtype).at[pos].set(
+                v.reshape((P * quota,) + v.shape[2:]), mode="drop")
+            for k, v in recv_cols.items()}
+    nvalid = jnp.minimum(jnp.sum(recv_counts), cap_out).astype(jnp.int32)
+    return Table(cols, nvalid), bufs.overflow
 
 
 def shuffle_table_pipelined(

@@ -86,8 +86,8 @@ _FABRIC_PROFILES = {"ici": ICI, "dcn": DCN, "host": HOST}
 
 # Fixed per-launch overhead of a pallas_call (dispatch + VMEM staging), and
 # the fraction of the jnp per-row cost the Pallas path saves on TPU (the
-# one-hot-matmul kernels replace scatter-adds the TPU lowers to serialized
-# updates). Both are calibration constants in the same spirit as
+# kernels replace scatter-adds the TPU lowers to serialized updates). Both
+# are calibration constants in the same spirit as
 # ``CostParams.gamma_s_per_row``; ``benchmarks/bench_kernels.py`` reports
 # measured speedups next to the thresholds these produce.
 _KERNEL_LAUNCH_S = 2e-6
@@ -102,10 +102,10 @@ _KERNEL_DTYPES = {
     "segment_reduce": ("int32", "uint32", "float32"),
 }
 
-# per-kernel pallas block sizes: rows per grid step. segment_reduce uses a
-# smaller block because its exactness contract sizes the one-hot matmul as
-# (block x block) (dense contiguous segment ids span <= block per block).
-_KERNEL_BLOCKS = {"hash_partition": 1024, "segment_reduce": 256}
+# per-kernel pallas block sizes: rows per grid step, each a whole number of
+# lane-dense (8, 128) int32 tiles. segment_reduce takes half the rows: its
+# lane scan keeps several block-sized temporaries live in VMEM.
+_KERNEL_BLOCKS = {"hash_partition": 65536, "segment_reduce": 32768}
 
 
 # -- Adaptive mid-stream re-planning knobs (ISSUE 9) -----------------------------

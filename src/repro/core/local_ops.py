@@ -105,9 +105,24 @@ def column_aggregate_local(table: Table, name: str, op: str):
 
 # -- sorting -------------------------------------------------------------------
 
+def _sorts_last(dtype) -> jax.Array:
+    """The value a stable sort places after every other: NaN for floats
+    (sorts put it last), the maximum otherwise."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.asarray(jnp.nan, dtype)
+    if dtype == jnp.bool_:
+        return jnp.asarray(True)
+    return jnp.asarray(jnp.iinfo(dtype).max, dtype)
+
+
 def local_sort(table: Table, key_columns: Sequence[str], descending: bool = False) -> Table:
-    """O(n log n) local sort; invalid rows stay at the tail (stable)."""
-    inv = ~valid_mask(table)
+    """O(n log n) local sort; invalid rows stay at the tail (stable).
+
+    Invalid rows carry the sorts-last value in every key instead of an
+    extra validity key (each key of a TPU sort multiplies what it costs to
+    compile): they form the input's tail, so a stable sort keeps them
+    behind any live row with the same keys."""
+    m = valid_mask(table)
     keys = []
     for name in reversed(key_columns):
         k = table.columns[name]
@@ -115,20 +130,43 @@ def local_sort(table: Table, key_columns: Sequence[str], descending: bool = Fals
             # order-reversing map: -x for floats, ~x for ints (exact, no
             # INT_MIN overflow).
             k = -k if jnp.issubdtype(k.dtype, jnp.floating) else jnp.bitwise_not(k)
-        keys.append(k)
-    keys.append(inv)  # primary: invalid rows last
+        keys.append(jnp.where(m, k, _sorts_last(k.dtype)))
     order = jnp.lexsort(tuple(keys))
     cols = {k: v[order] for k, v in table.columns.items()}
     return Table(cols, table.nvalid)
 
 
-def _sorted_by_key_hash(table: Table, key_columns: Sequence[str]):
-    """Sort rows by (valid desc, key hash, key columns...). Returns
-    (sorted_table, sorted_hash, order). Invalid rows at tail with hash=MAX."""
+def _hash_identifies_key(table: Table, key_columns: Sequence[str]) -> bool:
+    """True when equal hashes mean equal keys: one key column of at most
+    32 bits, whose normalized value the lowbias32 chain maps one-to-one."""
+    return (len(key_columns) == 1
+            and table.columns[key_columns[0]].dtype.itemsize <= 4)
+
+
+def _sort_hash(table: Table, key_columns: Sequence[str]) -> jax.Array:
+    """The sort key of :func:`_sorted_by_key_hash`: the key hash, with
+    ``0xFFFFFFFF`` for invalid rows.
+
+    Invalid rows are the table's tail, so a stable sort keeps them behind
+    live rows of the same hash. When the hash does not identify the key,
+    the key columns break ties, and live hashes are clamped below
+    ``0xFFFFFFFF`` so that no garbage key of an invalid row can sort ahead
+    of a live row."""
     h = hash_columns(table, key_columns)
-    m = valid_mask(table)
-    h = jnp.where(m, h, jnp.uint32(0xFFFFFFFF))
-    keys = [table.columns[n] for n in reversed(key_columns)] + [h, ~m]
+    if not _hash_identifies_key(table, key_columns):
+        h = jnp.minimum(h, jnp.uint32(0xFFFFFFFE))
+    return jnp.where(valid_mask(table), h, jnp.uint32(0xFFFFFFFF))
+
+
+def _sorted_by_key_hash(table: Table, key_columns: Sequence[str]):
+    """Sort rows stably by (:func:`_sort_hash`, key columns...) — by the
+    hash alone where it identifies the key: each key of a TPU sort
+    multiplies what it costs to compile. Returns (sorted_table,
+    sorted_hash, order); live rows first, equal keys adjacent."""
+    h = _sort_hash(table, key_columns)
+    keys = [h]
+    if not _hash_identifies_key(table, key_columns):
+        keys = [table.columns[n] for n in reversed(key_columns)] + keys
     order = jnp.lexsort(tuple(keys))
     cols = {k: v[order] for k, v in table.columns.items()}
     return Table(cols, table.nvalid), h[order], order
@@ -178,7 +216,7 @@ def _seg_reduce_dispatch(vals: jax.Array, seg: jax.Array, nseg: int, op: str) ->
     (docs/KERNELS.md)."""
     from ..kernels import ops as kernel_ops
 
-    return kernel_ops.segment_reduce(vals[:, None], seg, nseg, op=op)[:, 0]
+    return kernel_ops.segment_reduce(vals, seg, nseg, op=op)
 
 
 def agg_schema(aggs: Mapping[str, Sequence[str]]) -> list[tuple[str, str, str]]:
@@ -234,9 +272,8 @@ def local_groupby(
     def seg_reduce(vals, op):
         # combine leg of Combine-Shuffle-Reduce: dispatched to the Pallas
         # segment_reduce kernel when profitable (registry + cost model).
-        # seg is dense, contiguous and non-decreasing (cumsum of is_new;
-        # invalid rows -> the cap bucket at the tail), which is exactly the
-        # kernel path's exactness contract (max_segments = block).
+        # seg is non-decreasing (cumsum of is_new; invalid rows -> the cap
+        # bucket at the tail), which is the kernel path's precondition.
         if op == "min":
             vals = jnp.where(m, vals, _max_sentinel(vals.dtype))
         elif op == "max":
@@ -298,6 +335,26 @@ def finalize_groupby(table: Table, aggs: Mapping[str, Sequence[str]]) -> Table:
 
 # -- join (sort-based hash join, paper Table 4 Sort-Join) ----------------------
 
+def _hash_runs(lh: jax.Array, rh: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``searchsorted(lh, rh)`` on both sides: the run of the sorted ``lh``
+    equal to each ``rh``, as [lo, hi).
+
+    One search by sorting (the default binary search gathers over every
+    query once per step, seconds at 25M rows on a TPU); the run's end then
+    comes from a reverse running minimum over ``lh``."""
+    n = lh.shape[0]
+    if n == 0:
+        z = jnp.zeros(rh.shape, jnp.int32)
+        return z, z
+    lo = jnp.searchsorted(lh, rh, side="left", method="sort").astype(jnp.int32)
+    idx = jnp.arange(n, dtype=jnp.int32)
+    last = jnp.concatenate([lh[1:] != lh[:-1], jnp.ones((1,), bool)])
+    run_end = jax.lax.cummin(jnp.where(last, idx, n), axis=0, reverse=True) + 1
+    at = jnp.minimum(lo, n - 1)
+    hi = jnp.where((lo < n) & (lh[at] == rh), run_end[at], lo)
+    return lo, hi
+
+
 def local_join(
     left: Table,
     right: Table,
@@ -313,11 +370,12 @@ def local_join(
     """
     ls, lh, lorder = _sorted_by_key_hash(left, key_columns)
     rm = valid_mask(right)
-    rh = hash_columns(right, key_columns)
-    rh = jnp.where(rm, rh, jnp.uint32(0xFFFFFFFE))  # differs from left's pad
-    lo = jnp.searchsorted(lh, rh, side="left")
-    hi = jnp.searchsorted(lh, rh, side="right")
-    counts = (hi - lo).astype(jnp.int32)
+    rh = _sort_hash(right, key_columns)
+    lo, hi = _hash_runs(lh, rh)
+    # live left rows are the sorted prefix: never pair with the tail
+    lo = jnp.minimum(lo, ls.nvalid)
+    hi = jnp.minimum(hi, ls.nvalid)
+    counts = jnp.where(rm, hi - lo, 0).astype(jnp.int32)
     offs = jnp.cumsum(counts) - counts  # exclusive prefix
     total = offs[-1] + counts[-1]
 

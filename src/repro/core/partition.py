@@ -92,9 +92,8 @@ def hash_partition_ids(table: Table, key_columns: Sequence[str], num_partitions:
     if mode != "jnp":
         from .. import kernels
 
-        ku = jnp.stack([u32_normalize(table.columns[n]) for n in key_columns],
-                       axis=1)
-        dest, _ = kernels.hash_partition(ku, num_partitions, force=mode,
+        cols = [u32_normalize(table.columns[n]) for n in key_columns]
+        dest, _ = kernels.hash_partition(cols, num_partitions, force=mode,
                                          with_hist=False)
     else:
         h = hash_columns(table, key_columns)
@@ -145,13 +144,16 @@ def build_shuffle_buffers(table: Table, dest: jax.Array, num_partitions: int, qu
     P, cap = num_partitions, table.capacity
     order = jnp.argsort(dest, stable=True)  # groups rows by destination
     sdest = dest[order]
+    # where each destination's group starts (P + 2 binary searches; one
+    # per row would cost a gather per step over every row)
+    starts = jnp.searchsorted(sdest, jnp.arange(P + 2, dtype=sdest.dtype),
+                              side="left").astype(jnp.int32)
     # rank of each row within its destination group
-    group_start = jnp.searchsorted(sdest, sdest, side="left")
-    rank = jnp.arange(cap, dtype=jnp.int32) - group_start.astype(jnp.int32)
+    rank = jnp.arange(cap, dtype=jnp.int32) - starts[sdest]
     is_row = sdest < P  # drop-bucket (==P) excluded
     keep = is_row & (rank < quota)
     # raw per-destination counts (including overflowing rows)
-    raw = jnp.bincount(jnp.where(is_row, sdest, P), length=P + 1)[:P]
+    raw = jnp.diff(starts)[:P]
     counts = jnp.minimum(raw, quota).astype(jnp.int32)
     overflow = jnp.sum(raw - counts, dtype=jnp.int32)
 

@@ -10,9 +10,10 @@ for tests and benchmarks.
 
 The dataframe wrappers (:func:`hash_partition`, :func:`segment_reduce`)
 additionally handle the static-shape plumbing the hot paths need: padding
-arbitrary row counts up to a block multiple (with exact histogram
-correction) and merging block-local partials into per-segment outputs that
-match the jnp path bit-for-bit on every associative case.
+arbitrary row counts up to whole lane-dense blocks (with exact histogram
+correction) and gathering each segment's scanned reduction into
+per-segment outputs that match the jnp path bit-for-bit on every
+associative case.
 """
 
 from __future__ import annotations
@@ -68,50 +69,66 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk=128, force: str | None = None):
     return ref.ssd_scan_ref(x, dt, A, B, C, D, chunk=chunk)
 
 
+def _tile_block(block: int, n_rows: int) -> int:
+    """Grid block rows for a lane-dense kernel call: ``block`` rounded up
+    to whole (8, 128) int32 tiles, and no larger than ``n_rows`` rounded up
+    the same way (small inputs run as one padded block)."""
+    tile = 8 * _hp.LANES
+    up = lambda n: -(-max(n, 1) // tile) * tile  # noqa: E731
+    return min(up(block), up(n_rows))
+
+
+def _key_columns(keys) -> list:
+    """Key columns as a list of (N,) arrays: ``keys`` is a sequence of
+    columns, or an (N,) / (N, n_cols) array."""
+    if isinstance(keys, (list, tuple)):
+        return list(keys)
+    if keys.ndim == 1:
+        return [keys]
+    return [keys[:, c] for c in range(keys.shape[1])]
+
+
 def hash_partition(keys, num_partitions, *, block: int | None = None,
                    force: str | None = None, with_hist: bool = True):
     """Destination partition ids + histogram for the shuffle build side.
 
     Args:
-      keys: (N,) or (N, n_cols) integer/uint arrays (bitcast to uint32;
-        the dataframe path pre-normalizes other dtypes via
-        ``partition.u32_normalize`` so the kernel hash equals
-        ``partition.hash_columns`` bit-for-bit).
+      keys: a sequence of (N,) key columns, or an (N,) / (N, n_cols)
+        array; integer/uint columns (bitcast to uint32; the dataframe path
+        passes ``partition.u32_normalize``d columns so the kernel hash
+        equals ``partition.hash_columns`` bit-for-bit).
       num_partitions: P.
       block: pallas grid block rows (default from
-        ``cost_model.kernel_params``). N is padded up to a block multiple
-        internally; the histogram is corrected for the pad rows, so any N
-        is accepted.
+        ``cost_model.kernel_params``), rounded up to whole (8, 128) tiles.
+        N is padded up to a block multiple internally; the histogram is
+        corrected for the pad rows, so any N is accepted.
       force: pin "pallas" | "interpret" | "jnp"/"xla" (default: registry
         dispatch).
-      with_hist: False skips the (block x P) one-hot histogram work in
-        the kernel and returns ``hist=None`` — what
-        ``partition.hash_partition_ids`` uses, since destinations are all
-        the shuffle build needs.
+      with_hist: False skips the kernel's histogram accumulation and
+        returns ``hist=None`` — what ``partition.hash_partition_ids``
+        uses, since destinations are all the shuffle build needs.
 
     Returns:
       (dest (N,) int32, hist (P,) int32 | None) — bit-identical across
       modes.
     """
-    if keys.ndim == 1:
-        keys = keys[:, None]
-    N = keys.shape[0]
+    cols = _key_columns(keys)
+    (N,) = cols[0].shape
     mode = force or registry.resolve("hash_partition", N)
     if mode in ("jnp", "xla") or N == 0:
-        dest, hist = ref.hash_partition_ref(keys, num_partitions)
+        dest, hist = ref.hash_partition_ref(jnp.stack(cols, axis=1),
+                                            num_partitions)
         return dest, (hist if with_hist else None)
     if block is None:
         block = registry.current_params().block["hash_partition"]
-    blk = min(block, N)
+    blk = _tile_block(block, N)
     pad = (-N) % blk
-    ku = keys.astype(jnp.uint32)
+    cols = [c.astype(jnp.uint32) for c in cols]
     if pad:
-        ku = jnp.concatenate([ku, jnp.zeros((pad, ku.shape[1]), ku.dtype)])
-    dest, hist = _hp.hash_partition(ku, num_partitions, block=blk,
+        cols = [jnp.concatenate([c, jnp.zeros((pad,), c.dtype)]) for c in cols]
+    dest, hist = _hp.hash_partition(cols, num_partitions, block=blk,
                                     interpret=(mode == "interpret"),
                                     with_hist=with_hist)
-    if with_hist:
-        hist = jnp.sum(hist, axis=0)
     if pad:
         if with_hist:
             # pad rows are all-zero keys: one deterministic destination
@@ -126,85 +143,82 @@ def partition_histogram(keys, num_partitions, *, block: int | None = None,
     statistics layer's observation primitive (ISSUE 9).
 
     The same dispatched :func:`hash_partition` pass that computes
-    destination ids also accumulates the (P,) histogram in its one-hot
-    kernel leg; this wrapper returns just that histogram, so the adaptive
-    re-planner and ``patterns.quota_from_histogram`` consume the exact
-    per-partition row counts the shuffle is about to see (bit-identical
-    across pallas/interpret/jnp modes, and to the streaming runner's host
-    ``bincount`` mirror).
+    destination ids also accumulates the (P,) histogram in its kernel's
+    resident accumulator; this wrapper returns just that histogram, so the
+    adaptive re-planner and ``patterns.quota_from_histogram`` consume the
+    exact per-partition row counts the shuffle is about to see
+    (bit-identical across pallas/interpret/jnp modes, and to the streaming
+    runner's host ``bincount`` mirror).
     """
     _, hist = hash_partition(keys, num_partitions, block=block, force=force,
                              with_hist=True)
     return hist
 
 
-def segment_reduce_partials(values, seg_ids, *, max_segments=128, block=1024,
-                            op="sum", interpret=False):
+def segment_reduce_partials(values, seg_ids, *, block=32768, op="sum",
+                            interpret=False):
     """Re-export of :func:`segment_reduce.segment_reduce_partials` (the raw
-    combine kernel) so hot paths and tests import one module."""
-    return _sr.segment_reduce_partials(values, seg_ids,
-                                       max_segments=max_segments, block=block,
-                                       op=op, interpret=interpret)
+    combine kernel: a segmented inclusive scan) so hot paths and tests
+    import one module."""
+    return _sr.segment_reduce_partials(values, seg_ids, block=block, op=op,
+                                       interpret=interpret)
 
 
 def segment_reduce(values, seg_ids, num_segments, *, op="sum",
-                   max_segments: int | None = None, block: int | None = None,
-                   force: str | None = None):
-    """Segment reduction over sorted seg_ids: combine kernel + jnp merge.
+                   block: int | None = None, force: str | None = None):
+    """Segment reduction over sorted seg_ids: scan kernel + one write per
+    segment.
 
-    The groupby hot path (``local_ops.local_groupby``) calls this with
-    *dense contiguous* segment ids, for which the default sizing
-    ``max_segments = block`` makes the kernel path exact for any input
-    (a block of ``block`` sorted dense ids spans at most ``block``
-    segments). Values are padded to a block multiple with op-identity
-    fill; partials merge via ``jax.ops.segment_{sum,min,max}`` in the
-    value dtype, so integer results are bit-identical to the direct
-    scatter-add path (float sums reassociate — docs/KERNELS.md).
+    The groupby hot path (``local_ops.local_groupby``) calls this with one
+    (N,) value column and dense contiguous segment ids; any non-decreasing
+    ids work. The column runs through the segmented-scan kernel
+    (:func:`segment_reduce_partials`); the last row of every segment then
+    holds its complete reduction and is the one row written to that
+    segment's output slot. Integer results are bit-identical to the direct
+    scatter path (float sums reassociate — docs/KERNELS.md).
 
     Args:
-      values: (N, width) value rows, sorted by ``seg_ids``.
+      values: (N,) values sorted by ``seg_ids``, or (N, width): each
+        column reduced on its own.
       seg_ids: (N,) int32 non-decreasing segment ids.
-      num_segments: segments in the output; ids >= num_segments land in a
-        drop bucket (trimmed), matching the callers' overflow-bucket use.
+      num_segments: segments in the output; ids outside
+        ``[0, num_segments)`` are dropped, matching the callers'
+        overflow-bucket use.
       op: "sum" | "max" | "min".
-      max_segments / block: kernel sizing (defaults:
-        ``cost_model.kernel_params`` block; max_segments = block).
+      block: kernel grid block rows (default: ``cost_model.kernel_params``
+        block), rounded up to whole (8, 128) tiles.
       force: pin a mode; default dispatches via the registry.
 
     Returns:
-      (num_segments, width) array in the value dtype.
+      (num_segments,) or (num_segments, width) array in the value dtype;
+      segments with no rows hold the op identity (0 / +max / -max), as on
+      the jnp path.
     """
-    N, width = values.shape
-    mode = force or registry.resolve("segment_reduce", N, values.dtype)
+    if values.ndim == 2:
+        return jnp.stack(
+            [segment_reduce(values[:, c], seg_ids, num_segments, op=op,
+                            block=block, force=force)
+             for c in range(values.shape[1])], axis=1)
+    (N,) = values.shape
+    mode = force or registry.resolve("segment_reduce", N, values.dtype, op)
     if mode in ("jnp", "xla") or N == 0:
         return ref.segment_reduce_ref(values, seg_ids, num_segments, op=op)
     if block is None:
         block = registry.current_params().block["segment_reduce"]
-    blk = min(block, N)
-    if max_segments is None:
-        max_segments = blk
+    blk = _tile_block(block, N)
     pad = (-N) % blk
+    fill = _sr.identity(op, values.dtype)
+    seg_ids = seg_ids.astype(jnp.int32)
     if pad:
-        if op == "sum":
-            fill = jnp.zeros((), values.dtype)
-        elif op == "min":
-            fill = _sr._hi_sentinel(values.dtype)
-        else:
-            fill = _sr._lo_sentinel(values.dtype)
-        values = jnp.concatenate(
-            [values, jnp.full((pad, width), fill, values.dtype)])
-        # pad ids with num_segments: keeps the sort order and lands in the
-        # drop bucket below
+        values = jnp.concatenate([values, jnp.full((pad,), fill, values.dtype)])
+        # the largest id keeps the order sorted and is never an output row
         seg_ids = jnp.concatenate(
-            [seg_ids, jnp.full((pad,), num_segments, jnp.int32)])
-    psum, pseg = _sr.segment_reduce_partials(
-        values, seg_ids, max_segments=max_segments, block=blk, op=op,
-        interpret=(mode == "interpret"))
-    pseg = jnp.clip(pseg, 0, num_segments)  # ids past the end -> drop bucket
-    if op == "sum":
-        out = jax.ops.segment_sum(psum, pseg, num_segments=num_segments + 1)
-    elif op == "max":
-        out = jax.ops.segment_max(psum, pseg, num_segments=num_segments + 1)
-    else:
-        out = jax.ops.segment_min(psum, pseg, num_segments=num_segments + 1)
-    return out[:num_segments]
+            [seg_ids, jnp.full((pad,), jnp.iinfo(jnp.int32).max, jnp.int32)])
+    scan = _sr.segment_reduce_partials(values, seg_ids, block=blk, op=op,
+                                       interpret=(mode == "interpret"))
+    last = jnp.concatenate([seg_ids[1:] != seg_ids[:-1],
+                            jnp.ones((1,), bool)])
+    live = last & (seg_ids >= 0) & (seg_ids < num_segments)
+    dst = jnp.where(live, seg_ids, num_segments)  # everything else: dropped
+    out = jnp.full((num_segments,), fill, values.dtype)
+    return out.at[dst].set(scan, mode="drop")

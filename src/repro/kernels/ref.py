@@ -78,7 +78,7 @@ def segment_reduce_ref(values, seg_ids, num_segments, op="sum"):
 
     This is the "jnp" dispatch path of ``ops.segment_reduce`` and the
     semantics the kernel path must reproduce: integer ops are exact (wrap
-    like the kernel's integer matmul), min/max exact in every dtype."""
+    like the kernel's scan), min/max exact in every dtype."""
     if op == "sum":
         return jax.ops.segment_sum(values, seg_ids, num_segments=num_segments)
     if op == "max":

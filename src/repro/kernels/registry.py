@@ -37,6 +37,7 @@ import functools
 import os
 
 import jax
+import numpy as np
 
 from ..core import cost_model
 
@@ -106,7 +107,7 @@ def current_params() -> cost_model.KernelParams:
     return _params(jax.default_backend())
 
 
-def resolve(kernel: str, n_rows: int, dtype=None) -> str:
+def resolve(kernel: str, n_rows: int, dtype=None, op: str | None = None) -> str:
     """Pick the execution mode for one kernel call at trace time.
 
     Args:
@@ -114,6 +115,9 @@ def resolve(kernel: str, n_rows: int, dtype=None) -> str:
       n_rows: static row count of the call (the partition capacity).
       dtype: value dtype, for the kernel's supported-dtype gate (``None``
         skips the gate — hash_partition normalizes all dtypes itself).
+      op: the reduction (segment_reduce's "sum" | "min" | "max"); it only
+        labels the dispatch counter, every op lowers for every supported
+        dtype.
 
     Returns:
       "pallas" | "interpret" | "jnp". A forced ``"pallas"`` backend still
@@ -123,7 +127,7 @@ def resolve(kernel: str, n_rows: int, dtype=None) -> str:
     if kernel not in KERNEL_OPS:
         raise ValueError(f"unknown kernel {kernel!r}; expected {KERNEL_OPS}")
     decision = _decide(kernel, n_rows, dtype)
-    _note_dispatch(kernel, n_rows, decision)
+    _note_dispatch(kernel, n_rows, decision, dtype, op)
     return decision
 
 
@@ -138,9 +142,11 @@ def _decide(kernel: str, n_rows: int, dtype) -> str:
     return "pallas" if p.profitable(kernel, n_rows, dtype) else "jnp"
 
 
-def _note_dispatch(kernel: str, n_rows: int, decision: str) -> None:
+def _note_dispatch(kernel: str, n_rows: int, decision: str, dtype=None,
+                   op: str | None = None) -> None:
     """Record one dispatch decision: always counted in the global metrics
-    registry; while tracing, also attached to the enclosing span's
+    registry as ``kernels.dispatch.<kernel>[.<dtype>][.<op>].<decision>``;
+    while tracing, also attached to the enclosing span's
     ``kernel_dispatch`` attr (or an instant event when no span is open).
     Dispatch happens at trace time, so the cost is per compile, not per
     batch. Imports are deferred — ``repro.obs`` pulls in no engine modules,
@@ -148,11 +154,16 @@ def _note_dispatch(kernel: str, n_rows: int, decision: str) -> None:
     from ..obs import metrics as _metrics
     from ..obs import trace as _trace
 
-    _metrics.registry().counter(f"kernels.dispatch.{kernel}.{decision}").add(1)
+    labels = {"kernel": kernel}
+    if dtype is not None:
+        labels["dtype"] = np.dtype(dtype).name
+    if op is not None:
+        labels["op"] = op
+    name = ".".join(["kernels.dispatch", *labels.values(), decision])
+    _metrics.registry().counter(name).add(1)
     if _trace.enabled():
         sp = _trace.current_span()
-        entry = {"kernel": kernel, "n_rows": int(n_rows),
-                 "decision": decision}
+        entry = {**labels, "n_rows": int(n_rows), "decision": decision}
         if sp is not None:
             sp.attrs.setdefault("kernel_dispatch", []).append(entry)
         else:

@@ -166,7 +166,7 @@ def test_hash_partition_dest_only_variant():
 
 def test_hash_partition_parity_int64_folding():
     """64-bit keys fold hi^lo in u32_normalize before either path."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(7)
         keys = rng.integers(-(1 << 62), 1 << 62, size=(512,)).astype(np.int64)
         _hash_parity(keys, 16)

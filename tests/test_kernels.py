@@ -117,7 +117,7 @@ def test_segment_reduce_sweep(op, nseg, block):
     seg = np.sort(rng.integers(0, nseg, N)).astype(np.int32)
     vals = jnp.asarray(rng.normal(size=(N, W)), jnp.float32)
     got = ops.segment_reduce(vals, jnp.asarray(seg), nseg, op=op,
-                             max_segments=128, block=block, force="interpret")
+                             block=block, force="interpret")
     exp = ref.segment_reduce_ref(vals, jnp.asarray(seg), nseg, op=op)
     mask = np.isfinite(np.asarray(exp))
     np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(exp)[mask], atol=1e-4)
