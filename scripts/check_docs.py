@@ -233,7 +233,11 @@ def missing_obs_docs() -> list:
     import repro.obs as obs_pkg
 
     symbols = (list(obs_pkg.__all__)
-               + ["REPRO_TRACE", "to_chrome_trace", "model_report",
+               + ["REPRO_TRACE", "create_perfetto_trace", "model_report",
+                  "core.compile", "core.dispatch",
+                  "partition", "shuffle.pack", "shuffle.exchange",
+                  "shuffle.unpack", "local.join", "local.groupby",
+                  "local.unique", "local.sort", "trace.phase",
                   "peak_working_set_bytes", "retries:", "checkpoints",
                   "kernels.dispatch", "stream.decode", "stream.device_op",
                   "stream.stage", "service.morsel", "service.query",

@@ -127,12 +127,32 @@ def cached_op(ctx: "DDFContext", key: tuple, fn: Callable, arg_schemas: tuple) -
                  _kernel_registry.dispatch_signature())
     op = _OP_CACHE.get(cache_key)
     if op is None:
-        # compile misses are the expensive rare path — span them so traces
-        # separate trace/compile stalls from steady-state dispatches
-        with _trace.span("core.compile", op=str(key[0])):
-            op = _build_op(ctx, fn, arg_schemas)
+        op = _Op(_build_op(ctx, fn, arg_schemas), str(key[0]))
         _OP_CACHE.put(cache_key, op)
     return op
+
+
+class _Op:
+    """A compiled operator as the op cache holds it: the jitted shard_map
+    and its spans. ``jax.jit`` is lazy, so the first call is where the
+    program is traced, lowered and compiled (or loaded from the persistent
+    cache) and then enqueued: it is spanned ``core.compile``. Every later
+    call is one host enqueue, spanned ``core.dispatch``."""
+
+    __slots__ = ("jitted", "name", "fresh")
+
+    def __init__(self, jitted: Callable, name: str):
+        self.jitted = jitted
+        self.name = name
+        self.fresh = True
+
+    def __call__(self, *flat):
+        if self.fresh:
+            self.fresh = False
+            with _trace.span("core.compile", op=self.name):
+                return self.jitted(*flat)
+        with _trace.span("core.dispatch", op=self.name):
+            return self.jitted(*flat)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ...compat import axis_size as _compat_axis_size
+from ...obs.trace import phase
 from ..dataframe import Table, valid_mask
 from ..partition import build_shuffle_buffers
 
@@ -82,6 +83,7 @@ def barrier(axis) -> None:
 
 # -- table collectives ---------------------------------------------------------
 
+@phase("shuffle.exchange")
 def _all_to_all(x: jax.Array, axis) -> jax.Array:
     """(P, quota, ...) -> (P, quota, ...) where out[j] came from peer j."""
     return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=False)
@@ -115,15 +117,16 @@ def shuffle_table(table: Table, dest: jax.Array, axis, quota: int,
     # slot q of source s is live iff q < recv_counts[s]; it lands at
     # src_offset[s] + q (source-major, within-source stable), everything
     # else stays zero — placed by one scatter, not a compaction sort
-    cap_out = P * quota if capacity is None else capacity
-    src_offset = jnp.cumsum(recv_counts) - recv_counts
-    q = jnp.arange(quota, dtype=jnp.int32)
-    live = q[None, :] < recv_counts[:, None]
-    pos = jnp.where(live, src_offset[:, None] + q[None, :], cap_out).reshape(P * quota)
-    cols = {k: jnp.zeros((cap_out,) + v.shape[2:], v.dtype).at[pos].set(
-                v.reshape((P * quota,) + v.shape[2:]), mode="drop")
-            for k, v in recv_cols.items()}
-    nvalid = jnp.minimum(jnp.sum(recv_counts), cap_out).astype(jnp.int32)
+    with phase("shuffle.unpack"):
+        cap_out = P * quota if capacity is None else capacity
+        src_offset = jnp.cumsum(recv_counts) - recv_counts
+        q = jnp.arange(quota, dtype=jnp.int32)
+        live = q[None, :] < recv_counts[:, None]
+        pos = jnp.where(live, src_offset[:, None] + q[None, :], cap_out).reshape(P * quota)
+        cols = {k: jnp.zeros((cap_out,) + v.shape[2:], v.dtype).at[pos].set(
+                    v.reshape((P * quota,) + v.shape[2:]), mode="drop")
+                for k, v in recv_cols.items()}
+        nvalid = jnp.minimum(jnp.sum(recv_counts), cap_out).astype(jnp.int32)
     return Table(cols, nvalid), bufs.overflow
 
 
@@ -187,18 +190,20 @@ def shuffle_table_pipelined(
     # pad slots sit above ``quota`` and are never valid (counts <= quota).
     pad = K * cq - quota
     cols = bufs.columns
-    if pad:
-        cols = {
-            k: jnp.concatenate(
-                [v, jnp.zeros((P, pad) + v.shape[2:], v.dtype)], axis=1)
-            for k, v in cols.items()
-        }
-    chunks = {k: v.reshape((P, K, cq) + v.shape[2:]) for k, v in cols.items()}
+    with phase("shuffle.pack"):
+        if pad:
+            cols = {
+                k: jnp.concatenate(
+                    [v, jnp.zeros((P, pad) + v.shape[2:], v.dtype)], axis=1)
+                for k, v in cols.items()
+            }
+        chunks = {k: v.reshape((P, K, cq) + v.shape[2:]) for k, v in cols.items()}
 
-    out_cols = {
-        k: jnp.zeros((cap_out,) + v.shape[2:], v.dtype)
-        for k, v in bufs.columns.items()
-    }
+    with phase("shuffle.unpack"):
+        out_cols = {
+            k: jnp.zeros((cap_out,) + v.shape[2:], v.dtype)
+            for k, v in bufs.columns.items()
+        }
 
     def _send(k: int):
         return {name: _all_to_all(c[:, k], axis) for name, c in chunks.items()}
@@ -212,19 +217,22 @@ def shuffle_table_pipelined(
         # slot q of source s is valid iff q < recv_counts[s] and lands at
         # final position src_offset[s] + q (source-major, within-source
         # stable — the monolithic compact order).
-        q = k * cq + jnp.arange(cq, dtype=jnp.int32)  # (cq,)
-        valid = q[None, :] < recv_counts[:, None]  # (P, cq)
-        pos = src_offset[:, None].astype(jnp.int32) + q[None, :]
-        pos = jnp.where(valid, pos, cap_out).reshape(P * cq)
-        for name, v in recv.items():
-            flat = v.reshape((P * cq,) + v.shape[2:])
-            out_cols[name] = out_cols[name].at[pos].set(flat, mode="drop")
+        with phase("shuffle.unpack"):
+            q = k * cq + jnp.arange(cq, dtype=jnp.int32)  # (cq,)
+            valid = q[None, :] < recv_counts[:, None]  # (P, cq)
+            pos = src_offset[:, None].astype(jnp.int32) + q[None, :]
+            pos = jnp.where(valid, pos, cap_out).reshape(P * cq)
+            for name, v in recv.items():
+                flat = v.reshape((P * cq,) + v.shape[2:])
+                out_cols[name] = out_cols[name].at[pos].set(flat, mode="drop")
         recv = nxt
 
-    nvalid = jnp.minimum(jnp.sum(recv_counts), cap_out).astype(jnp.int32)
+    with phase("shuffle.unpack"):
+        nvalid = jnp.minimum(jnp.sum(recv_counts), cap_out).astype(jnp.int32)
     return Table(out_cols, nvalid), bufs.overflow
 
 
+@phase("shuffle.exchange")
 def _bruck_all_to_all(columns: dict, counts: jax.Array, axis):
     """Bruck all-to-all over ppermute rounds (Bruck et al. 1997; paper
     Table 3). Blocks are first rotated to relative order (slot j = block for

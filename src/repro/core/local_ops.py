@@ -22,6 +22,7 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import phase
 from .dataframe import Table, compact, max_sentinel, min_sentinel, valid_mask
 from .partition import hash_columns
 
@@ -115,6 +116,7 @@ def _sorts_last(dtype) -> jax.Array:
     return jnp.asarray(jnp.iinfo(dtype).max, dtype)
 
 
+@phase("local.sort")
 def local_sort(table: Table, key_columns: Sequence[str], descending: bool = False) -> Table:
     """O(n log n) local sort; invalid rows stay at the tail (stable).
 
@@ -158,6 +160,7 @@ def _sort_hash(table: Table, key_columns: Sequence[str]) -> jax.Array:
     return jnp.where(valid_mask(table), h, jnp.uint32(0xFFFFFFFF))
 
 
+@phase("local.sort")
 def _sorted_by_key_hash(table: Table, key_columns: Sequence[str]):
     """Sort rows stably by (:func:`_sort_hash`, key columns...) — by the
     hash alone where it identifies the key: each key of a TPU sort
@@ -185,6 +188,7 @@ def _adjacent_new_group(sorted_table: Table, key_columns: Sequence[str]) -> jax.
 
 # -- unique (hash dedup, paper Table 4: O(n), output O(nC)) --------------------
 
+@phase("local.unique")
 def local_unique(table: Table, key_columns: Sequence[str],
                  capacity: int | None = None, with_overflow: bool = False):
     """Deduplicate rows by key columns (first occurrence wins; hash-exact).
@@ -230,6 +234,7 @@ def agg_schema(aggs: Mapping[str, Sequence[str]]) -> list[tuple[str, str, str]]:
     return out
 
 
+@phase("local.groupby")
 def local_groupby(
     table: Table,
     key_columns: Sequence[str],
@@ -355,6 +360,7 @@ def _hash_runs(lh: jax.Array, rh: jax.Array) -> tuple[jax.Array, jax.Array]:
     return lo, hi
 
 
+@phase("local.join")
 def local_join(
     left: Table,
     right: Table,
@@ -409,6 +415,7 @@ def local_join(
     return res, overflow
 
 
+@phase("local.join")
 def local_anti_join(
     left: Table,
     right: Table,

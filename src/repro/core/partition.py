@@ -18,6 +18,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import phase
 from .dataframe import Table, valid_mask
 
 __all__ = [
@@ -76,6 +77,7 @@ def hash_columns(table: Table, key_columns: Sequence[str]) -> jax.Array:
     return h
 
 
+@phase("partition")
 def hash_partition_ids(table: Table, key_columns: Sequence[str], num_partitions: int) -> jax.Array:
     """Destination partition per row; invalid rows get ``num_partitions``
     (a drop bucket).
@@ -109,6 +111,7 @@ def _registry():
     return registry
 
 
+@phase("partition")
 def range_partition_ids(
     table: Table, key_column: str, pivots: jax.Array, num_partitions: int, descending: bool = False
 ) -> jax.Array:
@@ -133,6 +136,7 @@ class ShuffleBuffers(dict):
         self.overflow = overflow
 
 
+@phase("shuffle.pack")
 def build_shuffle_buffers(table: Table, dest: jax.Array, num_partitions: int, quota: int) -> ShuffleBuffers:
     """Lay live rows into fixed (P, quota) per-destination buffers.
 

@@ -3,8 +3,9 @@
 Three cooperating pieces (see docs/OBSERVABILITY.md):
 
 - :mod:`repro.obs.trace` — nestable, thread-safe spans with a
-  process-wide recorder and Chrome/Perfetto ``trace_event`` export;
-  near-zero cost while disabled.
+  process-wide recorder on the wall clock, mirrored into ``jax.profiler``
+  traces (Perfetto / TensorBoard), and the pattern-phase named scopes
+  of the compiled programs; near-zero cost while disabled.
 - :mod:`repro.obs.metrics` — a typed registry (counters, gauges, timing
   summaries) unifying the engine's previously ad-hoc counters; per-run
   sub-registries propagate into process totals (except on checkpoint

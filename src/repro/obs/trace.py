@@ -10,9 +10,15 @@ shared no-op handle, so the hot paths pay a single boolean check and no
 per-call object allocation. Enable with :func:`enable` / :func:`tracing`,
 or process-wide via the ``REPRO_TRACE=1`` environment variable.
 
-Recorded spans export as Chrome/Perfetto ``trace_event`` JSON via
-:meth:`Trace.to_chrome_trace` — load the saved file in
-https://ui.perfetto.dev or ``chrome://tracing``.
+One timeline with the device: while recording, a live span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, and :func:`now` counts
+wall-clock seconds, the clock of the profiler's host plane. Under
+``jax.profiler.trace(dir, create_perfetto_trace=True)`` the engine's
+spans and the device's ops land in one trace that Perfetto and
+TensorBoard open; retroactive spans line up with it through the shared
+clock. Inside compiled programs, :func:`phase` names the paper's pattern
+phases (``partition``, ``shuffle.*``, ``local.*``) as JAX named scopes,
+which reach every device op's ``op_name`` metadata.
 
 Intervals that do not nest on a call stack (a streaming stage suspended
 and resumed across service quanta, a query's whole lifetime closed from
@@ -23,8 +29,8 @@ thread's span stack.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
-import json
 import os
 import threading
 import time
@@ -41,14 +47,13 @@ __all__ = [
     "instant",
     "mark",
     "now",
+    "phase",
     "reset",
     "span",
     "summary",
     "tracing",
 ]
 
-_EPOCH = time.perf_counter()
-_PID = os.getpid()
 # backstop against unbounded growth in long-lived traced processes; the
 # drop count is surfaced on the Trace so truncation is never silent
 _MAX_EVENTS = 1_000_000
@@ -59,13 +64,51 @@ _events: list = []
 _dropped = 0
 _ids = itertools.count(1)
 _tls = threading.local()
+# jax.profiler.TraceAnnotation, imported on the first recorded span
+_Annotation = None
+# the monotonic counter's distance from the wall clock, taken once: spans
+# sit on the profiler's wall-clock timeline, and a step of the wall clock
+# later in the run moves none of their durations
+_WALL_NS = time.time_ns() - time.perf_counter_ns()
 
 
 def now() -> float:
-    """Seconds since the trace epoch (module import) — the spans' clock.
+    """Wall-clock seconds as of import, advanced by the monotonic counter
+    (``time.perf_counter_ns``) — the spans' clock, and the clock of
+    ``jax.profiler``'s host plane.
 
     Use with :func:`complete` to record intervals retroactively."""
-    return time.perf_counter() - _EPOCH
+    return (time.perf_counter_ns() + _WALL_NS) / 1e9
+
+
+def _annotation(name: str, attrs: dict):
+    """The profiler's twin of a live span; its scalar attrs become the
+    event's stats."""
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation as _Annotation
+    return _Annotation(name, **{k: v for k, v in attrs.items()
+                                if isinstance(v, (str, int, float, bool))})
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """Name a pattern phase inside a traced JAX function (always on).
+
+    Opens ``jax.named_scope`` for the phase's kind and then for the phase
+    itself — ``phase("shuffle.pack")`` scopes ops as ``shuffle/shuffle.pack``
+    — so the ``op_name`` metadata of every device op says which auxiliary,
+    communication or core local op it belongs to (paper §4). Scopes change
+    HLO metadata only, never the computation; usable as a decorator."""
+    import jax
+
+    kind = name.split(".", 1)[0]
+    with jax.named_scope(kind):
+        if kind == name:
+            yield
+        else:
+            with jax.named_scope(name):
+                yield
 
 
 def enabled() -> bool:
@@ -118,7 +161,7 @@ class Span:
     ``attrs["kernel_dispatch"]`` list."""
 
     __slots__ = ("sid", "parent", "name", "cat", "t0", "t1", "tid",
-                 "thread", "attrs")
+                 "thread", "attrs", "_ann")
 
     def __init__(self, name: str, cat: str | None = None,
                  attrs: dict | None = None):
@@ -131,6 +174,7 @@ class Span:
         self.tid = 0
         self.thread = ""
         self.attrs = {} if attrs is None else attrs
+        self._ann = None
 
     def set(self, **attrs):
         """Attach attributes to this span; returns the span."""
@@ -151,11 +195,16 @@ class Span:
         self.tid = t.ident or 0
         self.thread = t.name
         stack.append(self)
+        self._ann = _annotation(self.name, self.attrs)
+        self._ann.__enter__()
         self.t0 = now()
         return self
 
     def __exit__(self, *exc):
         self.t1 = now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         stack = getattr(_tls, "stack", [])
         if stack and stack[-1] is self:
             stack.pop()
@@ -274,22 +323,6 @@ def tracing() -> _Tracing:
     return _Tracing()
 
 
-def _jsonable(v):
-    if v is None or isinstance(v, (bool, int, float, str)):
-        return v
-    if isinstance(v, dict):
-        return {str(k): _jsonable(x) for k, x in v.items()}
-    if isinstance(v, (list, tuple)):
-        return [_jsonable(x) for x in v]
-    item = getattr(v, "item", None)  # numpy scalars
-    if callable(item):
-        try:
-            return _jsonable(item())
-        except (TypeError, ValueError):
-            pass
-    return repr(v)
-
-
 class Trace:
     """An immutable snapshot of recorded spans (see :func:`get_trace`).
 
@@ -302,38 +335,6 @@ class Trace:
 
     def __len__(self):
         return len(self.spans)
-
-    def to_chrome_trace(self) -> dict:
-        """The trace as a Chrome/Perfetto ``trace_event`` JSON object.
-
-        Returns the dict form (``{"traceEvents": [...]}`` with complete
-        ``"X"`` events, microsecond timestamps, and thread-name metadata);
-        ``json.dump`` it or use :meth:`save` to write a file Perfetto and
-        ``chrome://tracing`` load directly."""
-        events = []
-        threads: dict[int, str] = {}
-        for sp in self.spans:
-            if sp.thread and sp.tid not in threads:
-                threads[sp.tid] = sp.thread
-        for tid, tname in threads.items():
-            events.append({"name": "thread_name", "ph": "M", "pid": _PID,
-                           "tid": tid, "args": {"name": tname}})
-        for sp in self.spans:
-            events.append({"name": sp.name,
-                           "cat": sp.cat or "repro",
-                           "ph": "X",
-                           "ts": sp.t0 * 1e6,
-                           "dur": sp.duration_s * 1e6,
-                           "pid": _PID,
-                           "tid": sp.tid,
-                           "args": _jsonable(sp.attrs)})
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def save(self, path: str) -> str:
-        """Write :meth:`to_chrome_trace` JSON to ``path``; returns it."""
-        with open(path, "w") as f:
-            json.dump(self.to_chrome_trace(), f)
-        return path
 
     def summary(self) -> dict:
         """Aggregate by span name: ``{name: {"count", "total_s"}}``."""

@@ -1,11 +1,15 @@
 """Unified observability layer (tracing + metrics + cost-model checks):
-span nesting and thread safety, the disabled fast path, Chrome-trace JSON
-schema, per-pattern predicted-vs-observed records across eager/streaming
-paths, bit-identity of profiled runs, and the admission controller's
-learned working-set corrections."""
+span nesting and thread safety, the disabled fast path, the clock shared
+with jax.profiler, the compile and dispatch spans, the pattern-phase
+scopes in the compiled programs, per-pattern predicted-vs-observed
+records across eager/streaming paths, bit-identity of profiled runs, and
+the admission controller's learned working-set corrections."""
 
-import json
+import glob
+import os
+import re
 import threading
+import time
 
 import jax
 import numpy as np
@@ -127,24 +131,152 @@ def test_disabled_mode_null_span():
     assert trace.summary()["enabled"] is False
 
 
-def test_chrome_trace_schema(tmp_path, traced):
+def _host_events(trace_dir, names):
+    """``{name: (start_ns, end_ns)}`` of the profiler's host-plane events,
+    on the wall clock (the planes count from ``profile_start_time``)."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    data = ProfileData.from_file(path)
+    origin = next(v for pl in data.planes for k, v in pl.stats
+                  if k == "profile_start_time")
+    return {e.name: (origin + e.start_ns, origin + e.end_ns)
+            for pl in data.planes if pl.name.startswith("/host:")
+            for line in pl.lines for e in line.events if e.name in names}
+
+
+def test_spans_share_the_profilers_clock(tmp_path):
+    """A live span lands in the profiler's host plane; it and a
+    retroactive span agree with the recorder's intervals within 1 ms."""
+    with trace.tracing(), jax.profiler.trace(str(tmp_path)):
+        mark = trace.mark()
+        t0 = trace.now()
+        with trace.span("clock.live", rows=3):
+            time.sleep(0.02)
+        trace.complete("clock.retro", t0)
+        with jax.profiler.TraceAnnotation("clock.twin"):
+            t1 = trace.now()
+            time.sleep(0.02)
+            trace.complete("clock.retro_twin", t1)
+    spans = {sp.name: sp for sp in trace.get_trace(since=mark).spans}
+    plane = _host_events(str(tmp_path), {"clock.live", "clock.twin"})
+    assert set(plane) == {"clock.live", "clock.twin"}
+
+    def close(sp, ev):
+        return (abs(sp.t0 * 1e9 - ev[0]) < 1e6
+                and abs(sp.t1 * 1e9 - ev[1]) < 1e6)
+
+    assert close(spans["clock.live"], plane["clock.live"])
+    # the retroactive span opened just before the live one
+    live = plane["clock.live"]
+    retro = spans["clock.retro"]
+    assert abs(retro.t0 * 1e9 - live[0]) < 1e6 and retro.t1 * 1e9 >= live[1]
+    assert close(spans["clock.retro_twin"], plane["clock.twin"])
+
+
+def test_span_durations_ignore_wall_clock_steps(traced, monkeypatch):
+    """A step of the wall clock (an NTP correction) inside a span moves
+    neither its duration nor the recorder's order of time."""
     mark, _ = traced
-    with trace.span("parent", bytes=123):
-        with trace.span("kid"):
-            pass
-    trace.instant("blip")
-    path = tmp_path / "trace.json"
-    trace.get_trace(since=mark).save(str(path))
-    doc = json.loads(path.read_text())
-    assert set(doc) == {"traceEvents", "displayTimeUnit"}
-    events = doc["traceEvents"]
-    xs = [e for e in events if e["ph"] == "X"]
-    assert {e["name"] for e in xs} >= {"parent", "kid", "blip"}
-    for e in xs:
-        # required Chrome trace_event fields, all JSON-able
-        assert {"name", "ph", "ts", "dur", "pid", "tid", "args"} <= set(e)
-        assert e["dur"] >= 0
-    assert any(e["ph"] == "M" and e["name"] == "thread_name" for e in events)
+    wall = time.time_ns()
+    monkeypatch.setattr(time, "time_ns", lambda: wall - 3_600 * 10**9)
+    t0 = trace.now()
+    with trace.span("clock.step"):
+        time.sleep(0.01)
+    (sp,) = trace.get_trace(since=mark).spans
+    assert sp.t0 >= t0 and 0.01 <= sp.duration_s < 1.0
+    assert abs(sp.t0 - wall / 1e9) < 60
+
+
+# -- the engine's compile and dispatch spans ------------------------------------
+
+def _fresh_join(ctx, monkeypatch, seed):
+    """A join whose op is built on its first call: the op cache starts
+    empty, so that call misses once."""
+    from repro.core import api
+
+    monkeypatch.setattr(api, "_OP_CACHE", api._LRUCache())
+    L = DDF.from_numpy(_table(300, seed=seed), ctx, capacity=900, mode="eager")
+    R = DDF.from_numpy(_table(300, seed=seed + 1), ctx, capacity=900,
+                       mode="eager")
+    return lambda: jax.block_until_ready(
+        L.join(R, on=("k",), strategy="shuffle"))
+
+
+def _builds() -> int:
+    from repro.core import api
+
+    return api._OP_CACHE.stats()["misses"]
+
+
+def _spans_named(mark, *names):
+    return [sp for sp in trace.get_trace(since=mark).spans if sp.name in names]
+
+
+def test_compile_span_covers_the_first_call(ctx, traced, monkeypatch):
+    mark, _ = traced
+    run = _fresh_join(ctx, monkeypatch, seed=11)
+    run()
+    (first,) = _spans_named(mark, "core.compile")
+    assert first.attrs["op"] == "join" and first.duration_s > 0
+    assert _builds() == 1
+    # the first call traced the program: trace-time decisions land in it
+    assert first.attrs["kernel_dispatch"]
+    mark = trace.mark()
+    run()
+    assert _spans_named(mark, "core.compile") == []
+    (dispatch,) = _spans_named(mark, "core.dispatch")
+    assert dispatch.attrs == {"op": "join"}
+    assert _builds() == 1
+
+
+def test_compile_span_off_records_nothing(ctx, monkeypatch):
+    assert not trace.enabled()
+    run = _fresh_join(ctx, monkeypatch, seed=13)
+    mark = trace.mark()
+    run()
+    run()
+    assert len(trace.get_trace(since=mark)) == 0
+    assert _builds() == 1  # the op is still built once
+
+
+# -- pattern-phase scopes in the compiled programs --------------------------------
+
+def _op_names(monkeypatch, run) -> set:
+    """Every ``op_name`` in the compiled HLO of the ops ``run`` builds."""
+    from repro.core import api
+
+    monkeypatch.setattr(api, "_OP_CACHE", api._LRUCache())
+    texts = []
+    call = api._Op.__call__
+
+    def compiled_first(self, *flat):
+        texts.append(self.jitted.lower(*flat).compile().as_text())
+        return call(self, *flat)
+
+    monkeypatch.setattr(api._Op, "__call__", compiled_first)
+    run()
+    return {n for t in texts for n in re.findall(r'op_name="([^"]*)"', t)}
+
+
+@pytest.mark.parametrize("op,core", [("join", "local.join"),
+                                     ("groupby", "local.groupby")])
+def test_phase_scopes_reach_the_hlo(ctx, tables, monkeypatch, op, core):
+    L, R = tables
+    if op == "join":
+        def run():
+            return L.join(R, on=("k",), strategy="shuffle")
+    else:
+        def run():
+            return L.groupby(("k",), {"v": ["sum"]})
+    paths = _op_names(monkeypatch, run)
+    segments = {seg for p in paths for seg in p.split("/")}
+    assert {"partition", "shuffle", "shuffle.pack", "shuffle.unpack",
+            "local", core} <= segments
+    # each child sits under its kind
+    assert any("/shuffle/shuffle.pack/" in p for p in paths)
+    assert any(f"/local/{core}/" in p for p in paths)
 
 
 # -- metrics registry ---------------------------------------------------------
