@@ -89,6 +89,19 @@ def _all_to_all(x: jax.Array, axis) -> jax.Array:
     return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=False)
 
 
+def _blocks_to_rows(recv: jax.Array, offsets: jax.Array, n: int) -> jax.Array:
+    """``n`` zero rows with block ``recv[s]`` written from row ``offsets[s]``,
+    in ascending ``s``: a later block overwrites an earlier one's tail."""
+    def copy_block(s, out):
+        block = jax.lax.dynamic_index_in_dim(recv, s, 0, keepdims=False)
+        return jax.lax.dynamic_update_slice_in_dim(out, block, offsets[s], 0)
+
+    # int32 bounds, as in ``partition._runs_to_rows``: a loop the compiler
+    # can count, so a one-trip loop is inlined
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(recv.shape[0]), copy_block,
+                             jnp.zeros((n,) + recv.shape[2:], recv.dtype))
+
+
 def shuffle_table(table: Table, dest: jax.Array, axis, quota: int,
                   capacity: int | None = None, algorithm: str = "native") -> tuple[Table, jax.Array]:
     """AllToAll shuffle of live rows to ``dest`` partitions (paper §3.1/§5.1).
@@ -114,18 +127,19 @@ def shuffle_table(table: Table, dest: jax.Array, axis, quota: int,
     else:
         recv_cols = {k: _all_to_all(v, axis) for k, v in bufs.columns.items()}
         recv_counts = _all_to_all(bufs.counts.reshape(P, 1), axis).reshape(P)
-    # slot q of source s is live iff q < recv_counts[s]; it lands at
-    # src_offset[s] + q (source-major, within-source stable), everything
-    # else stays zero — placed by one scatter, not a compaction sort
+    # source s's live slots recv[s, :recv_counts[s]] land at src_offset[s]
+    # (source-major, within-source stable), one block copy per source in
+    # ascending s over zeros: block s's tail past recv_counts[s] is zero
+    # (pack zero-fills it) and block s+1 overwrites it; the last block's
+    # tail stays zero
     with phase("shuffle.unpack"):
         cap_out = P * quota if capacity is None else capacity
         src_offset = jnp.cumsum(recv_counts) - recv_counts
-        q = jnp.arange(quota, dtype=jnp.int32)
-        live = q[None, :] < recv_counts[:, None]
-        pos = jnp.where(live, src_offset[:, None] + q[None, :], cap_out).reshape(P * quota)
-        cols = {k: jnp.zeros((cap_out,) + v.shape[2:], v.dtype).at[pos].set(
-                    v.reshape((P * quota,) + v.shape[2:]), mode="drop")
-                for k, v in recv_cols.items()}
+        # src_offset[s] <= s * quota, so in a buffer of at least P * quota
+        # rows no block runs past the end: dynamic_update_slice would clamp
+        # such a start silently and shift the block onto its predecessor
+        n = max(P * quota, cap_out)
+        cols = {k: _blocks_to_rows(v, src_offset, n)[:cap_out] for k, v in recv_cols.items()}
         nvalid = jnp.minimum(jnp.sum(recv_counts), cap_out).astype(jnp.int32)
     return Table(cols, nvalid), bufs.overflow
 

@@ -136,37 +136,57 @@ class ShuffleBuffers(dict):
         self.overflow = overflow
 
 
+def _runs_to_rows(srt: jax.Array, starts: jax.Array, counts: jax.Array, quota: int) -> jax.Array:
+    """(P, quota, ...) rows: row d is ``srt[starts[d] : starts[d] + quota]``,
+    zero from slot ``counts[d]`` on."""
+    P, rest = counts.shape[0], srt.shape[1:]
+    slot = jnp.arange(quota, dtype=jnp.int32).reshape((quota,) + (1,) * len(rest))
+
+    def copy_run(d, buf):
+        run = jax.lax.dynamic_slice_in_dim(srt, starts[d], quota)
+        run = jnp.where(slot < counts[d], run, jnp.zeros((), srt.dtype))
+        return jax.lax.dynamic_update_index_in_dim(buf, run, d, 0)
+
+    # int32 bounds: under 64-bit types Python-int bounds give an int64
+    # counter, which the TPU compiler splits into two words and then cannot
+    # count, so even a one-trip loop (P == 1) would stay a loop
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(P), copy_run,
+                             jnp.zeros((P, quota) + rest, srt.dtype))
+
+
 @phase("shuffle.pack")
 def build_shuffle_buffers(table: Table, dest: jax.Array, num_partitions: int, quota: int) -> ShuffleBuffers:
     """Lay live rows into fixed (P, quota) per-destination buffers.
 
-    Stable within destination (preserves row order). Rows whose destination
-    bucket is full are counted in ``overflow`` and dropped — callers size
-    ``quota`` from sampled histograms (see ``repro.core.patterns``) so that
-    overflow is zero in practice, and can assert on it.
+    A stable argsort groups rows by destination, so destination ``d``'s rows
+    are the contiguous run ``sorted[starts[d] : starts[d] + raw[d]]``. Its
+    buffer row is one dynamic slice of ``quota`` rows from ``starts[d]``,
+    zeroed from ``counts[d]`` on: a copy of the run, with no per-row index.
+    Stable within destination (preserves row order); slots past ``counts``
+    are zero. Drop-bucket rows (``dest == P``) sort last and are never kept.
+    Rows beyond a destination's ``quota`` fall outside its slice; they are
+    counted in ``overflow`` and dropped — callers size ``quota`` from sampled
+    histograms (see ``repro.core.patterns``) so that overflow is zero in
+    practice, and can assert on it.
     """
-    P, cap = num_partitions, table.capacity
+    P = num_partitions
     order = jnp.argsort(dest, stable=True)  # groups rows by destination
     sdest = dest[order]
     # where each destination's group starts (P + 2 binary searches; one
     # per row would cost a gather per step over every row)
     starts = jnp.searchsorted(sdest, jnp.arange(P + 2, dtype=sdest.dtype),
                               side="left").astype(jnp.int32)
-    # rank of each row within its destination group
-    rank = jnp.arange(cap, dtype=jnp.int32) - starts[sdest]
-    is_row = sdest < P  # drop-bucket (==P) excluded
-    keep = is_row & (rank < quota)
     # raw per-destination counts (including overflowing rows)
     raw = jnp.diff(starts)[:P]
     counts = jnp.minimum(raw, quota).astype(jnp.int32)
     overflow = jnp.sum(raw - counts, dtype=jnp.int32)
 
-    scatter_d = jnp.where(keep, sdest, P)  # out-of-bounds rows -> dropped
-    scatter_r = jnp.where(keep, rank, quota)
     cols = {}
     for name, col in table.columns.items():
-        buf = jnp.zeros((P, quota) + col.shape[1:], col.dtype)
-        cols[name] = buf.at[scatter_d, scatter_r].set(col[order], mode="drop")
+        # ``quota`` pad rows: a start is at most the capacity, so no slice
+        # runs past the end, where dynamic_slice would clamp its start
+        pad = jnp.zeros((quota,) + col.shape[1:], col.dtype)
+        cols[name] = _runs_to_rows(jnp.concatenate([col[order], pad]), starts, counts, quota)
     return ShuffleBuffers(cols, counts, overflow)
 
 
