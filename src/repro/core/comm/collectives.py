@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ...compat import axis_size as _compat_axis_size
+from ...obs import metrics
 from ...obs.trace import phase
 from ..dataframe import Table, valid_mask
 from ..partition import build_shuffle_buffers
@@ -89,6 +90,15 @@ def _all_to_all(x: jax.Array, axis) -> jax.Array:
     return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=False)
 
 
+def _count_slots(table: Table, P: int, quota: int) -> None:
+    """Trace-time counters of the plan's padding, from static shapes: the
+    ``P * quota`` slots a shuffle builds against the capacity it was given
+    (equal at P = 1, about twice it under ``default_quota`` at P > 1)."""
+    reg = metrics.registry()
+    reg.counter("shuffle.slot_rows").add(P * quota)
+    reg.counter("shuffle.input_rows").add(table.capacity)
+
+
 def _blocks_to_rows(recv: jax.Array, offsets: jax.Array, n: int) -> jax.Array:
     """``n`` zero rows with block ``recv[s]`` written from row ``offsets[s]``,
     in ascending ``s``: a later block overwrites an earlier one's tail."""
@@ -121,6 +131,7 @@ def shuffle_table(table: Table, dest: jax.Array, axis, quota: int,
     (stable), preserving within-source order.
     """
     P = axis_size(axis)
+    _count_slots(table, P, quota)
     bufs = build_shuffle_buffers(table, dest, P, quota)
     if algorithm == "bruck":
         recv_cols, recv_counts = _bruck_all_to_all(bufs.columns, bufs.counts, axis)
@@ -192,6 +203,7 @@ def shuffle_table_pipelined(
     P = axis_size(axis)
     K = max(min(int(num_chunks), quota), 1)
     cq = -(-quota // K)  # per-chunk quota (ceil)
+    _count_slots(table, P, quota)
     bufs = build_shuffle_buffers(table, dest, P, quota)
     cap_out = (P * quota) if capacity is None else capacity
 

@@ -8,6 +8,10 @@ sizes, and checks that the compiler accepted it as a Mosaic kernel
 cannot catch what these do: tiles the hardware cannot hold, ops the
 compiler cannot lower, and layouts that pad past HBM.
 
+One more compiles the shuffle's all-to-all of an int64 column over a
+described 2x2 host at the four-worker cell's quota: how the compiler
+sends 64-bit words is what the benchmark's exchange readers count.
+
 The topology is described in a fixture, never at import: only one process
 at a time may load the TPU library.
 """
@@ -31,8 +35,8 @@ _V5E_PROGRAM_HBM_BYTES = 15.75e9
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One described v5e chip; the persistent compile cache is off while
+def v5e_2x2():
+    """A described v5e 2x2 host; the persistent compile cache is off while
     compiling for it (its entries cannot be read back without a chip)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -45,9 +49,15 @@ def v5e():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    """One described v5e chip."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _rows(kernel: str) -> tuple[int, int]:
@@ -88,3 +98,31 @@ def test_segment_reduce_compiles_for_v5e(v5e, dtype, op):
     _compile_checked(
         lambda v, s: _sr.segment_reduce_partials(v, s, block=block, op=op),
         vals, segs)
+
+
+def test_int64_all_to_all_compiles_for_v5e_2x2(v5e_2x2):
+    """The shuffle's exchange of one int64 column at the four-worker
+    cell's quota (``default_quota(25M, 4)`` slots per destination): the
+    compiler accepts it over the 2x2 and sends the column as its two u32
+    words, all four chips in one group."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.compat import shard_map
+    from repro.core.comm.collectives import _all_to_all
+    from repro.core.partition import default_quota
+    from repro.launch.mesh import make_mesh
+
+    quota = default_quota(CONFIG.rows_per_worker, 4)
+    with jax.enable_x64(True):
+        mesh = make_mesh((4,), ("data",), devices=v5e_2x2.devices)
+        exchange = shard_map(lambda x: _all_to_all(x.reshape(4, quota), "data").reshape(-1),
+                             mesh=mesh, in_specs=PartitionSpec("data"),
+                             out_specs=PartitionSpec("data"), check_vma=False)
+        col = jax.ShapeDtypeStruct((16 * quota,), jnp.int64,
+                                   sharding=NamedSharding(mesh, PartitionSpec("data")))
+        text = jax.jit(exchange).lower(col).compile().as_text()
+    ops = [line for line in text.splitlines() if " all-to-all(" in line]
+    assert len(ops) == 2, ops
+    for line in ops:
+        assert f"u32[4,1,{quota}]" in line.split(" all-to-all(")[0], line
+        assert "replica_groups={{0,1,2,3}}" in line, line
