@@ -9,8 +9,10 @@ can exceed capacity, an ``overflow`` counter.
 Design notes (DESIGN.md §7.1):
 - Join expansion uses ``jnp.repeat(..., total_repeat_length)`` — the
   static-shape equivalent of Arrow's variable-length take.
-- Rows are matched on a 32-bit key hash and *verified on emission* against the
-  actual key columns, so hash collisions cost capacity, never correctness.
+- A join on one integer (or bool) key column matches rows on the key's own
+  value, so every expanded pair is a true match. Any other key is matched on
+  a 32-bit key hash and *verified on emission* against the actual key
+  columns, so hash collisions cost capacity, never correctness.
 - Multi-column keys sort lexicographically (hash, col1, col2, ...), which
   makes equal keys adjacent for dedup/groupby adjacency logic.
 """
@@ -22,6 +24,7 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..obs import metrics
 from ..obs.trace import phase
 from .dataframe import Table, compact, max_sentinel, min_sentinel, valid_mask
 from .partition import hash_columns
@@ -173,6 +176,31 @@ def _sorted_by_key_hash(table: Table, key_columns: Sequence[str]):
     order = jnp.lexsort(tuple(keys))
     cols = {k: v[order] for k, v in table.columns.items()}
     return Table(cols, table.nvalid), h[order], order
+
+
+def _exact_key(left: Table, right: Table, key_columns: Sequence[str]) -> bool:
+    """True when the key's own value is an exact sort key: one integer or
+    bool column, of one dtype on both sides. Floats are not (``-0.0 ==
+    0.0`` while a sort separates them, and NaN never equals itself)."""
+    if len(key_columns) != 1:
+        return False
+    dt = left.columns[key_columns[0]].dtype
+    return (dt == right.columns[key_columns[0]].dtype
+            and (jnp.issubdtype(dt, jnp.integer) or dt == jnp.bool_))
+
+
+@phase("local.sort")
+def _sorted_by_key(table: Table, key: str):
+    """Sort rows stably by the value of one integer or bool ``key``, with
+    invalid rows carrying the sorts-last value (as in :func:`local_sort`):
+    live rows first, equal keys adjacent in input order. The ``key`` column
+    is the sorted key, which the sort returns beside the order (no
+    gather)."""
+    k = jnp.where(valid_mask(table), table.columns[key], _sorts_last(table.columns[key].dtype))
+    sk, order = jax.lax.sort((k, jnp.arange(table.capacity, dtype=jnp.int32)),
+                             num_keys=1, is_stable=True)
+    cols = {n: sk if n == key else v[order] for n, v in table.columns.items()}
+    return Table(cols, table.nvalid)
 
 
 def _adjacent_new_group(sorted_table: Table, key_columns: Sequence[str]) -> jax.Array:
@@ -340,9 +368,10 @@ def finalize_groupby(table: Table, aggs: Mapping[str, Sequence[str]]) -> Table:
 
 # -- join (sort-based hash join, paper Table 4 Sort-Join) ----------------------
 
-def _hash_runs(lh: jax.Array, rh: jax.Array) -> tuple[jax.Array, jax.Array]:
+def _equal_runs(lh: jax.Array, rh: jax.Array) -> tuple[jax.Array, jax.Array]:
     """``searchsorted(lh, rh)`` on both sides: the run of the sorted ``lh``
-    equal to each ``rh``, as [lo, hi).
+    equal to each ``rh``, as [lo, hi). ``lh`` is a sorted key: a key hash
+    or an exact key's value.
 
     One search by sorting (the default binary search gathers over every
     query once per step, seconds at 25M rows on a TPU); the run's end then
@@ -370,14 +399,31 @@ def local_join(
 ) -> tuple[Table, jax.Array]:
     """Inner equi-join. Returns (result, overflow = pairs beyond capacity).
 
-    Left is sorted by key hash; each right row binary-searches its hash run;
-    pair expansion via total_repeat_length; emitted pairs verified against the
-    real key columns (collision-exact).
+    Left is sorted by a sort key, and each right row finds the run of left
+    rows with its sort key; pairs expand via total_repeat_length,
+    right-row-major, with the left rows of a key in input order.
+
+    - One integer or bool key column: the sort key is the key's value, so
+      every pair of a run is a true match and the first ``min(total,
+      capacity)`` pairs are the result; ``overflow`` is the true excess.
+    - Any other key: the sort key is the key hash. A run can hold keys whose
+      hash collides, so pairs are verified against the real key columns and
+      the survivors compacted (collision-exact; false pairs use capacity and
+      count in ``overflow``).
+
+    The path taken adds 1 to the trace-time counter ``join.exact_runs`` or
+    ``join.verified_runs``.
     """
-    ls, lh, lorder = _sorted_by_key_hash(left, key_columns)
+    exact = _exact_key(left, right, key_columns)
+    metrics.registry().counter("join.exact_runs" if exact else "join.verified_runs").add(1)
+    if exact:
+        ls = _sorted_by_key(left, key_columns[0])
+        lk, rk = ls.columns[key_columns[0]], right.columns[key_columns[0]]
+    else:
+        ls, lk, _ = _sorted_by_key_hash(left, key_columns)
+        rk = _sort_hash(right, key_columns)
     rm = valid_mask(right)
-    rh = _sort_hash(right, key_columns)
-    lo, hi = _hash_runs(lh, rh)
+    lo, hi = _equal_runs(lk, rk)
     # live left rows are the sorted prefix: never pair with the tail
     lo = jnp.minimum(lo, ls.nvalid)
     hi = jnp.minimum(hi, ls.nvalid)
@@ -391,13 +437,6 @@ def local_join(
     within = out_pos - offs[out_r]
     out_l = jnp.clip(lo[out_r].astype(jnp.int32) + within, 0, left.capacity - 1)
 
-    emit = out_pos < total
-    # verify true key equality (hash-collision guard) + validity
-    lvalid = jnp.arange(left.capacity, dtype=jnp.int32) < ls.nvalid
-    for name in key_columns:
-        emit = emit & (ls.columns[name][out_l] == right.columns[name][out_r])
-    emit = emit & lvalid[out_l] & rm[out_r]
-
     cols: dict[str, jax.Array] = {}
     for name in key_columns:
         cols[name] = ls.columns[name][out_l]
@@ -409,9 +448,20 @@ def local_join(
             out_name = name if name not in cols else f"{name}{suffix}"
             cols[out_name] = v[out_r]
 
+    overflow = jnp.maximum(total - capacity, 0)
+    if exact:
+        # every pair in [0, total) is a true match: the pairs are the result
+        return Table(cols, jnp.minimum(total, capacity)), overflow
+
+    emit = out_pos < total
+    # verify true key equality (hash-collision guard) + validity
+    lvalid = jnp.arange(left.capacity, dtype=jnp.int32) < ls.nvalid
+    for name in key_columns:
+        emit = emit & (ls.columns[name][out_l] == right.columns[name][out_r])
+    emit = emit & lvalid[out_l] & rm[out_r]
+
     res = Table(cols, jnp.asarray(capacity, jnp.int32))
     res = compact(res, emit, capacity=capacity)
-    overflow = jnp.maximum(total - capacity, 0)
     return res, overflow
 
 
@@ -431,8 +481,8 @@ def local_anti_join(
     lu = local_unique(left, key_columns) if dedup_left else left
     ru = local_unique(right, key_columns)
     ls, _, _ = _sorted_by_key_hash(lu, key_columns)
-    # Join the deduplicated keys (collision-exact thanks to emit-verify in
-    # local_join) and scatter hit marks back onto left rows by row index.
+    # Join the deduplicated keys (collision-exact: local_join's runs are exact
+    # or verified) and scatter hit marks back onto left rows by row index.
     # Both sides are deduplicated, so the pair count is bounded by
     # ls.capacity — no overflow possible.
     member = jnp.zeros((ls.capacity,), bool)

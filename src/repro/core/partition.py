@@ -39,9 +39,10 @@ def u32_normalize(x: jax.Array) -> jax.Array:
     """Canonical uint32 view of any column dtype, pre-hash.
 
     64-bit ints fold hi^lo (so the engine is independent of
-    ``jax_enable_x64``), bools widen, floats bitcast (equal floats hash
-    equal). Shared by the jnp hash chain below and the Pallas
-    ``kernels.hash_partition`` build side, so both hash bit-identically.
+    ``jax_enable_x64``), bools widen, floats bitcast with ``-0.0`` taken as
+    ``0.0`` (equal floats hash equal). Shared by the jnp hash chain below
+    and the Pallas ``kernels.hash_partition`` build side, so both hash
+    bit-identically.
     """
     if x.dtype in (jnp.int64, jnp.uint64):
         u = x.astype(jnp.uint64)
@@ -49,7 +50,9 @@ def u32_normalize(x: jax.Array) -> jax.Array:
     if x.dtype == jnp.bool_:
         return x.astype(jnp.uint32)
     if jnp.issubdtype(x.dtype, jnp.floating):
-        return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+        f = x.astype(jnp.float32)
+        f = jnp.where(f == 0, jnp.zeros_like(f), f)
+        return jax.lax.bitcast_convert_type(f, jnp.uint32)
     return x.astype(jnp.uint32)
 
 
